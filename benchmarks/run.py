@@ -30,6 +30,7 @@ PRs); ``scripts/check_bench_schema.py`` validates every appended row.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
 import traceback
@@ -98,4 +99,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

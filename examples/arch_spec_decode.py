@@ -15,6 +15,7 @@ import jax
 
 from repro.configs.registry import ARCH_IDS, smoke_config
 from repro.core import EngineSpec, ModelBundle, make_controller, make_engine
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 
 
@@ -46,4 +47,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

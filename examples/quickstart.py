@@ -12,6 +12,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks.common import get_corpus, trained_pair
 from repro.core import EngineSpec, make_controller, make_engine
 from repro.data.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -38,4 +39,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

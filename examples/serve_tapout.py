@@ -13,6 +13,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks.common import get_corpus, trained_pair
 from repro.core import EngineSpec, StaticGamma, make_controller
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.engine import SpecServer
 
 
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -14,6 +14,7 @@ import jax
 
 from repro.configs.registry import ARCH_IDS, smoke_config
 from repro.data.synthetic import SyntheticCorpus
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.training.checkpoint import save_checkpoint
 from repro.training.optimizer import OptConfig
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

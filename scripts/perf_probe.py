@@ -61,4 +61,6 @@ if __name__ == "__main__":
     import os
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=512")
+    # the virtual devices are CPU ones: never take a TPU that is present
+    os.environ["JAX_PLATFORMS"] = "cpu"
     sys.exit(main())

@@ -88,6 +88,12 @@ def _sample(logits, rng, temperature: float):
 
 
 def _probs(logits, temperature: float):
+    if temperature == 0.0:
+        # the exact t -> 0 limit: one-hot at the argmax.  A softmax at a tiny
+        # temperature scales the logits to ~1e4-1e5; on the TPU its argmax
+        # then came out as token 0 for rows whose logits had a clear max.
+        return jax.nn.one_hot(jnp.argmax(logits, axis=-1), logits.shape[-1],
+                              dtype=jnp.float32)
     t = max(temperature, 1e-4)
     return jax.nn.softmax(logits.astype(jnp.float32) / t, axis=-1)
 

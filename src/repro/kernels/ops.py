@@ -1,14 +1,16 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Dispatch policy:
-  * on TPU: compiled Pallas kernels (the hardware target);
-  * on CPU: ``interpret=True`` executes the kernel body in Python — used by
-    the correctness tests; model code defaults to the XLA paths instead
-    (``repro.models.attention.sdpa`` / ``ssm.ssd_chunked``) because
-    interpret mode is orders of magnitude slower.
+No serving or training program reaches these wrappers: the model code runs
+attention through the XLA paths (``repro.models.attention.sdpa``) and the
+SSD scan through ``repro.models.ssm.ssd_chunked`` (whose ``use_kernel``
+flag nothing sets).  The kernels are validated against the pure-jnp
+oracles in ``kernels/ref.py`` in interpret mode only; the TPU compiler
+refuses every one of them at real widths (ROADMAP A2).
 
-Set ``repro.kernels.ops.FORCE_INTERPRET = True`` (tests do) to exercise the
-kernels on CPU.
+Dispatch policy: on a TPU backend the kernels are compiled by Mosaic.
+Anywhere else a call raises, unless the caller asked for the interpreter
+with ``repro.kernels.ops.FORCE_INTERPRET = True`` (the kernel tests and
+``benchmarks/bench_kernels.py`` do) -- there is no silent fallback.
 """
 from __future__ import annotations
 
@@ -26,7 +28,15 @@ FORCE_INTERPRET = False
 
 
 def _interpret() -> bool:
-    return FORCE_INTERPRET or jax.default_backend() != "tpu"
+    if FORCE_INTERPRET:
+        return True
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"Pallas kernels compile only for TPU (backend is {backend!r}); "
+            "set repro.kernels.ops.FORCE_INTERPRET = True to run them in "
+            "interpret mode")
+    return False
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

@@ -16,8 +16,11 @@ Usage:
 """
 import os
 
-# Force 512 virtual host devices BEFORE jax (imported below) initializes.
+# Force 512 virtual host devices BEFORE jax (imported below) initializes,
+# and pin the CPU: on a machine with a TPU, JAX would otherwise take the
+# chip and fail to build the 256-device mesh from it.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json

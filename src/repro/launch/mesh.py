@@ -24,12 +24,22 @@ __all__ = [
 HOST_DEVICE_FLAG = "--xla_force_host_platform_device_count"
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis typed Auto.  JAX 0.9 defaults new
+    meshes to Explicit axes, under which an unannotated gather such as the
+    embedding lookup ``params["embed"][tokens]`` raises
+    ``ShardingTypeError``; the models rely on GSPMD propagation plus
+    ``constrain`` hints, which is the Auto contract."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """The deployment meshes: (16, 16) ("data","model") single pod, or
     (2, 16, 16) ("pod","data","model") across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, data: int = 1):
@@ -40,7 +50,7 @@ def make_host_mesh(model: int = 1, data: int = 1):
     n = len(jax.devices())
     model = max(1, min(model, n))
     data = max(1, min(data, n // model))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def forced_host_env(n_devices: int, base: Optional[dict] = None) -> dict:

@@ -1,7 +1,9 @@
 """Self-attention: GQA/MQA/MHA, optional sliding window, qk-norm, QKV bias.
 
-Two XLA execution paths (the Pallas TPU kernels in ``repro.kernels`` are the
-hardware target; on CPU they are validated in interpret mode only):
+Two XLA execution paths, and these are what every serving and training
+program runs, on the TPU too.  The Pallas kernels in ``repro.kernels`` are
+reached by no program path: they pass their interpret-mode tests, but the
+TPU compiler refuses each of them at real widths (ROADMAP A2).
 
   * ``naive``     — materializes the (Sq, Sk) score matrix; used for small
                     shapes and as the reference.

@@ -34,6 +34,17 @@ def test_flash_attention_sweep(B, H, G, Sq, Sk, D, dtype):
                                np.asarray(exp, np.float32), **_tol(dtype))
 
 
+def test_interpret_mode_only_when_asked(monkeypatch):
+    """Off the TPU a kernel call raises unless interpret mode was asked
+    for: there is no silent fallback."""
+    assert jax.default_backend() != "tpu"
+    monkeypatch.setattr(ops, "FORCE_INTERPRET", False)
+    with pytest.raises(RuntimeError, match="FORCE_INTERPRET"):
+        ops._interpret()
+    monkeypatch.setattr(ops, "FORCE_INTERPRET", True)
+    assert ops._interpret() is True
+
+
 def test_flash_attention_window():
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (1, 2, 64, 32))

@@ -49,11 +49,22 @@ def test_resolve_spec_drops_indivisible():
     assert spec2[0] is None and spec2[1] is None  # 6%4, 5%2
 
 
+def test_host_mesh_axes_are_auto():
+    """Meshes the launch layer builds leave sharding to GSPMD (Auto axes):
+    under JAX's Explicit default the embedding gather raises."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(data=1, model=1)
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
 _MULTIDEV = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs.registry import smoke_config
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.shardings import batch_shardings, params_shardings
     from repro.models import transformer as T
     from repro.models.sharding import use_mesh
@@ -61,7 +72,7 @@ _MULTIDEV = textwrap.dedent("""
     from repro.training.train_loop import make_train_step
 
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(data=2, model=4)
     cfg = smoke_config("qwen3-moe-235b-a22b").replace(vocab_size=512)
     with use_mesh(mesh):
         params = T.init_params(cfg, jax.random.PRNGKey(0))

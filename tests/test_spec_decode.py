@@ -101,3 +101,18 @@ def test_modeled_cost_monotone(tiny_dense_pair):
     r1 = eng.generate(PROMPT, 10)
     r2 = eng.generate(PROMPT, 30)
     assert r2.modeled_cost > r1.modeled_cost > 0
+
+
+def test_greedy_probs_are_the_exact_argmax_one_hot():
+    """At temperature 0 the target/draft distribution is the one-hot of the
+    logits' argmax, computed exactly: no tiny-temperature softmax, whose
+    scaled logits (~1e5 here) are where a backend can lose the argmax."""
+    from repro.core.spec_decode import _probs
+    logits = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 4099)) * 4.0
+    logits = logits.astype(jnp.bfloat16)
+    p = np.asarray(_probs(logits, 0.0))
+    want = np.eye(4099, dtype=np.float32)[np.asarray(
+        logits.astype(jnp.float32)).argmax(-1)]
+    np.testing.assert_array_equal(p, want)
+    warm = np.asarray(_probs(logits, 1.0))
+    np.testing.assert_allclose(warm.sum(-1), 1.0, rtol=1e-5)
