@@ -1,6 +1,6 @@
 """qwen3-4b [dense] — qk_norm, GQA kv=8.
 
-[hf:Qwen/Qwen3-8B family card]  36L d_model=2560 32H (kv=8) d_ff=9728
+[hf:Qwen/Qwen3-4B]  36L d_model=2560 32H (kv=8) d_ff=9728
 vocab=151936.
 """
 from repro.models import ModelConfig
@@ -19,5 +19,5 @@ CONFIG = ModelConfig(
     qk_norm=True,
     rope_theta=1_000_000.0,
     block_pattern=("attn",),
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-4B",
 )

@@ -18,6 +18,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import transformer as T
 from repro.models.cache import (POOL_LEAF_KEYS, BlockAllocator,
@@ -1874,10 +1875,12 @@ class PagedSpecEngine(_ShardingMixin):
         into the pool."""
         bundle = self.draft if which == "draft" else self.target
         spec = self.dspec if which == "draft" else self.tspec
-        lane = self._lane_view(cache, slot)
-        lane = chunk_prefill_paged(bundle.params, bundle.cfg, spec, lane,
-                                   jnp.asarray(tokens, jnp.int32), n_valid)
-        return self._merge_lane(cache, lane, slot)
+        with TraceAnnotation("engine.prefill_chunk",
+                             model=int(which == "target"), tokens=n_valid):
+            lane = self._lane_view(cache, slot)
+            lane = chunk_prefill_paged(bundle.params, bundle.cfg, spec, lane,
+                                       jnp.asarray(tokens, jnp.int32), n_valid)
+            return self._merge_lane(cache, lane, slot)
 
     def _prefill_lane(self, which: str, cache, slot: int, tokens: List[int]):
         """Monolithic prefill = the FULL chunk schedule run back to back.
@@ -2377,14 +2380,16 @@ class PagedSpecEngine(_ShardingMixin):
             seq = self.slots[s]["seq"]
             in_toks[s] = seq[-2:]
             last_toks[s, 0] = seq[-1]
-        keys = self._next_rng(2 * B)
-        ft = self._fused_tick(
-            self.draft.params, self.target.params, self.dcache, self.tcache,
-            jnp.asarray(in_toks), jnp.asarray(last_toks),
-            jnp.asarray(arm_mat), jnp.float32(self.controller.lam),
-            keys[:B], keys[B:], jnp.asarray(active),
-            jnp.asarray(L, jnp.int32), jnp.asarray(self._dlen, jnp.int32),
-            jnp.asarray(self._tlen, jnp.int32))
+        with TraceAnnotation("engine.launch_dispatch"):
+            keys = self._next_rng(2 * B)
+            ft = self._fused_tick(
+                self.draft.params, self.target.params, self.dcache,
+                self.tcache, jnp.asarray(in_toks), jnp.asarray(last_toks),
+                jnp.asarray(arm_mat), jnp.float32(self.controller.lam),
+                keys[:B], keys[B:], jnp.asarray(active),
+                jnp.asarray(L, jnp.int32),
+                jnp.asarray(self._dlen, jnp.int32),
+                jnp.asarray(self._tlen, jnp.int32))
         self.dcache, self.tcache = ft.dcache, ft.tcache
         self._pending = {"act_idx": act_idx, "active": active,
                          "arm_mat": arm_mat, "L": L, "ft": ft}
@@ -2403,6 +2408,9 @@ class PagedSpecEngine(_ShardingMixin):
         g = self.gamma_max
         c_d = self.draft.cost_per_token
         c_t = self.target.cost_per_token
+        with TraceAnnotation("engine.flush_wait"):
+            jax.block_until_ready((ft.n_drafted, ft.n_accepted,
+                                   ft.out_tokens))
         nd = np.asarray(ft.n_drafted)
         m = np.asarray(ft.n_accepted)
         out_all = np.asarray(ft.out_tokens)

@@ -389,13 +389,14 @@ def _verify_core(params, cfg, spec: CacheSpec, cache, last_token, drafted,
     B = last_token.shape[0]
     inp = jnp.concatenate([last_token, drafted], axis=1)       # (B, gamma+1)
     logits, cache = T.step(params, cfg, inp, cache, spec, all_logits=True)
-    m, out = _accept_and_outputs(
-        logits, drafted, n_drafted, qprobs, rng,
-        gamma_max=gamma_max, temperature=temperature, greedy=greedy,
-        split_fn=lambda r: tuple(jax.random.split(r)),
-        uniform_fn=lambda k: jax.random.uniform(k, (B, gamma_max)),
-        categorical_fn=lambda d, k: jax.random.categorical(
-            k, jnp.log(jnp.maximum(d, 1e-30))).astype(jnp.int32))
+    with jax.named_scope("accept"):
+        m, out = _accept_and_outputs(
+            logits, drafted, n_drafted, qprobs, rng,
+            gamma_max=gamma_max, temperature=temperature, greedy=greedy,
+            split_fn=lambda r: tuple(jax.random.split(r)),
+            uniform_fn=lambda k: jax.random.uniform(k, (B, gamma_max)),
+            categorical_fn=lambda d, k: jax.random.categorical(
+                k, jnp.log(jnp.maximum(d, 1e-30))).astype(jnp.int32))
     return VerifyResult(m, out, m + 1, cache)
 
 
@@ -470,14 +471,15 @@ def verify_session_paged(params, cfg, spec, cache, last_tokens, drafted,
         last_tokens, drafted, n_drafted, qprobs, rngs, active)
     inp = jnp.concatenate([last_tokens, drafted], axis=1)       # (B, g+1)
     logits, cache = T.paged_step(params, cfg, inp, cache, spec, all_logits=True)
-    m, out = _accept_and_outputs(
-        logits, drafted, n_drafted, qprobs, rngs,
-        gamma_max=gamma_max, temperature=temperature, greedy=greedy,
-        split_fn=_split_rows,
-        uniform_fn=jax.vmap(lambda k: jax.random.uniform(k, (gamma_max,))),
-        categorical_fn=lambda d, k: jax.vmap(
-            lambda d1, k1: jax.random.categorical(
-                k1, jnp.log(jnp.maximum(d1, 1e-30))))(d, k).astype(jnp.int32))
+    with jax.named_scope("accept"):
+        m, out = _accept_and_outputs(
+            logits, drafted, n_drafted, qprobs, rngs,
+            gamma_max=gamma_max, temperature=temperature, greedy=greedy,
+            split_fn=_split_rows,
+            uniform_fn=jax.vmap(lambda k: jax.random.uniform(k, (gamma_max,))),
+            categorical_fn=lambda d, k: jax.vmap(
+                lambda d1, k1: jax.random.categorical(
+                    k1, jnp.log(jnp.maximum(d1, 1e-30))))(d, k).astype(jnp.int32))
     m = jnp.where(active, m, 0)
     out = jnp.where(active[:, None], out, 0)
     m, out, n_out = _lane_constrain(m, out, jnp.where(active, m + 1, 0))
@@ -508,7 +510,8 @@ def chunk_prefill_paged(params, cfg, spec, lane, tokens, n_valid):
     feed the same chunk schedule through this one program.
     """
     start = lane["lengths"]
-    _, lane = T.paged_step(params, cfg, tokens, lane, spec)
+    with jax.named_scope("prefill"):
+        _, lane = T.paged_step(params, cfg, tokens, lane, spec)
     return paged_rollback(lane, start + jnp.asarray(n_valid, jnp.int32))
 
 
@@ -623,7 +626,11 @@ def _fused_tick_core(dparams, tparams, cfg_d, cfg_t, dspec: CacheSpec,
                      gamma_max: int, temperature: float, greedy: bool,
                      n_prompt_tokens: int, paged: bool):
     """ONE device program per serving tick: input-side rollback -> draft
-    while-loop -> verify forward -> accept -> output-side rollback.
+    while-loop -> verify forward -> accept -> output-side rollback.  Each
+    stage runs under a ``jax.named_scope`` (``rollback``, ``draft``,
+    ``verify``, and ``accept`` inside ``verify``), so a profiler trace
+    names the device ops of each stage (docs/serving.md, "Tracing a
+    server").
 
     Calls the exact traced bodies of the synchronous primitives
     (``draft_session_batched`` / ``verify_session_batched`` or their paged
@@ -645,19 +652,23 @@ def _fused_tick_core(dparams, tparams, cfg_d, cfg_t, dspec: CacheSpec,
                   verify_session_batched).__wrapped__
 
     # input-side rollback: re-feed the last two accepted tokens
-    dcaches_in = rb(dcaches, jnp.where(active, lengths - 2, dkeep))
-    dres = draft_raw(dparams, cfg_d, dspec, dcaches_in, in_tokens, arm_mat,
-                     lam, drngs, active, arms=arms, gamma_max=gamma_max,
-                     temperature=temperature,
-                     n_prompt_tokens=n_prompt_tokens)
-    vres = verify_raw(tparams, cfg_t, tspec, tcaches, last_tokens,
-                      dres.tokens, dres.n_drafted, dres.qprobs, vrngs,
-                      active, gamma_max=gamma_max, temperature=temperature,
-                      greedy=greedy)
+    with jax.named_scope("rollback"):
+        dcaches_in = rb(dcaches, jnp.where(active, lengths - 2, dkeep))
+    with jax.named_scope("draft"):
+        dres = draft_raw(dparams, cfg_d, dspec, dcaches_in, in_tokens,
+                         arm_mat, lam, drngs, active, arms=arms,
+                         gamma_max=gamma_max, temperature=temperature,
+                         n_prompt_tokens=n_prompt_tokens)
+    with jax.named_scope("verify"):
+        vres = verify_raw(tparams, cfg_t, tspec, tcaches, last_tokens,
+                          dres.tokens, dres.n_drafted, dres.qprobs, vrngs,
+                          active, gamma_max=gamma_max,
+                          temperature=temperature, greedy=greedy)
     m = vres.n_accepted
     # output-side rollback (cache invariant: pos/length == len(seq) - 1 fed)
-    tcache = rb(vres.cache, jnp.where(active, lengths + m, tkeep))
-    dcache = rb(dres.cache, jnp.where(active, lengths + m - 1, dkeep))
+    with jax.named_scope("rollback"):
+        tcache = rb(vres.cache, jnp.where(active, lengths + m, tkeep))
+        dcache = rb(dres.cache, jnp.where(active, lengths + m - 1, dkeep))
     return FusedTick(dres.n_drafted, m, vres.out_tokens, dres.entropies,
                      dres.signals, dcache, tcache)
 

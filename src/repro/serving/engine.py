@@ -65,6 +65,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.controller import Controller
 from repro.core.engine import (EngineSpec, GenResult, ModelBundle,
@@ -208,14 +209,21 @@ class SpecServer:
         req = self.requests[rid]
         frozen = self._frozen.get(rid)
         prompt = frozen["seq"] if frozen else req.prompt
-        if not self.paged:
-            self.engine.open_stream(slot, prompt, req.eos_id)
-        else:
-            opener = (self.engine.open_stream_chunked if chunked
-                      else self.engine.open_stream)
-            opener(slot, prompt, req.eos_id,
-                   reserve_tokens=self._reserve_tokens(rid),
-                   resume_from=frozen["res"] if frozen else None)
+        skipped = getattr(self.engine, "prefill_tokens_skipped", 0)
+        wait_us = int((time.perf_counter() - req.submitted_at) * 1e6)
+        with TraceAnnotation("server.admit", rid=rid,
+                             prompt_tokens=len(prompt),
+                             queue_wait_us=wait_us) as span:
+            if not self.paged:
+                self.engine.open_stream(slot, prompt, req.eos_id)
+            else:
+                opener = (self.engine.open_stream_chunked if chunked
+                          else self.engine.open_stream)
+                opener(slot, prompt, req.eos_id,
+                       reserve_tokens=self._reserve_tokens(rid),
+                       resume_from=frozen["res"] if frozen else None)
+            span.set_metadata(adopted_tokens=getattr(
+                self.engine, "prefill_tokens_skipped", 0) - skipped)
         if frozen is not None:
             del self._frozen[rid]
             self.resume_events += 1
@@ -257,23 +265,33 @@ class SpecServer:
         the device, but its begin/update call sequence — and so its state
         — is exactly what back-to-back synchronous ticks produce.  Returns
         the request ids that completed this tick (i.e. in the flushed
-        tick t-1; several streams can finish in one tick)."""
-        self.engine.session_step_flush()
-        finished = self._release_finished()
-        before = getattr(self.engine, "prefill_tokens_computed", None)
-        self.scheduler.schedule(self)
-        if before is not None:
-            # per-tick decode stall from admission prefill (chunked
-            # schedulers bound this; monolithic admission pays the whole
-            # non-cached prompt suffix at once)
-            self.max_prefill_tokens_per_tick = max(
-                self.max_prefill_tokens_per_tick,
-                self.engine.prefill_tokens_computed - before)
-        if self._slot_rid:
-            self.peak_concurrency = max(self.peak_concurrency,
-                                        len(self._slot_rid))
-            self.engine.session_step_launch()
-        self.tick_count += 1
+        tick t-1; several streams can finish in one tick).
+
+        Each phase runs under a ``jax.profiler.TraceAnnotation`` named
+        after what it calls, all inside ``server.step`` (docs/serving.md,
+        "Tracing a server")."""
+        with TraceAnnotation("server.step", active=len(self._slot_rid),
+                             queued=len(self.queue)):
+            with TraceAnnotation("engine.session_step_flush"):
+                self.engine.session_step_flush()
+            with TraceAnnotation("server.release_finished"):
+                finished = self._release_finished()
+            before = getattr(self.engine, "prefill_tokens_computed", None)
+            with TraceAnnotation("scheduler.schedule"):
+                self.scheduler.schedule(self)
+            if before is not None:
+                # per-tick decode stall from admission prefill (chunked
+                # schedulers bound this; monolithic admission pays the
+                # whole non-cached prompt suffix at once)
+                self.max_prefill_tokens_per_tick = max(
+                    self.max_prefill_tokens_per_tick,
+                    self.engine.prefill_tokens_computed - before)
+            if self._slot_rid:
+                self.peak_concurrency = max(self.peak_concurrency,
+                                            len(self._slot_rid))
+                with TraceAnnotation("engine.session_step_launch"):
+                    self.engine.session_step_launch()
+            self.tick_count += 1
         return finished
 
     def _release_finished(self) -> List[int]:
