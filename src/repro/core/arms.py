@@ -25,19 +25,28 @@ import jax.numpy as jnp
 
 
 def signals_from_probs(probs, prev_sqrt_entropy, lam, pos):
-    """probs: (..., V) draft distribution for the token just drafted."""
-    p = probs.astype(jnp.float32)
-    ent = -jnp.sum(jnp.where(p > 0, p * jnp.log(p), 0.0), axis=-1)
-    top2 = jax.lax.top_k(p, 2)[0]
-    return {
-        "entropy": ent,
-        "sqrt_entropy": jnp.sqrt(jnp.maximum(ent, 0.0)),
-        "prev_sqrt_entropy": prev_sqrt_entropy,
-        "top1": top2[..., 0],
-        "top2": top2[..., 1],
-        "lam": lam,
-        "pos": pos,
-    }
+    """probs: (..., V) draft distribution for the token just drafted.
+
+    ``top1``/``top2`` are the two largest entries, as ``lax.top_k(p, 2)``
+    gives them (a tied maximum gives ``top2 == top1``), found by
+    reductions over the vocabulary: the TPU compiler lowers ``top_k``
+    inside the draft loop to a full sort of it."""
+    with jax.named_scope("signals"):
+        p = probs.astype(jnp.float32)
+        ent = -jnp.sum(jnp.where(p > 0, p * jnp.log(p), 0.0), axis=-1)
+        top1 = jnp.max(p, axis=-1)
+        first = jnp.argmax(p, axis=-1)[..., None]
+        hole = jnp.arange(p.shape[-1]) == first
+        top2 = jnp.max(jnp.where(hole, -jnp.inf, p), axis=-1)
+        return {
+            "entropy": ent,
+            "sqrt_entropy": jnp.sqrt(jnp.maximum(ent, 0.0)),
+            "prev_sqrt_entropy": prev_sqrt_entropy,
+            "top1": top1,
+            "top2": top2,
+            "lam": lam,
+            "pos": pos,
+        }
 
 
 SIGNAL_VECTOR_DIM = 6

@@ -1,4 +1,5 @@
 """Arm decision rules on crafted distributions (paper Table 1 semantics)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -81,3 +82,39 @@ def test_signal_vector_shape():
     v = signal_vector(sig)
     assert v.shape == (1, 6)
     assert np.isfinite(np.asarray(v)).all()
+
+
+def _softmax_rows(shape, seed=0):
+    logits = np.random.default_rng(seed).normal(0, 3, shape)
+    return np.asarray(jax.nn.softmax(jnp.asarray(logits, jnp.float32), -1))
+
+
+def _row(v, at, vals):
+    p = np.zeros((1, v), np.float32)
+    p[0, at] = vals
+    return p
+
+
+@pytest.mark.parametrize("probs", [
+    pytest.param(lambda: _softmax_rows((4, 151936)), id="softmax-B-V151936"),
+    pytest.param(lambda: _softmax_rows((2, 3, 151936), 1),
+                 id="softmax-B-S-V151936"),
+    pytest.param(lambda: _softmax_rows((5, 257), 2), id="softmax-B-V257"),
+    pytest.param(lambda: _softmax_rows((2, 4, 257), 3), id="softmax-B-S-V257"),
+    pytest.param(lambda: _row(257, [3, 200, 7], [0.4, 0.4, 0.2]),
+                 id="two-tied-maxima"),
+    pytest.param(lambda: _row(257, [5], [1.0]), id="one-hot"),
+    pytest.param(lambda: _row(257, [0, 100, 256], [0.2, 0.3, 0.5]),
+                 id="max-at-last-index"),
+])
+def test_top1_top2_match_lax_top_k_bitwise(probs):
+    p = jnp.asarray(probs())
+    lead = p.shape[:-1]
+    sig = jax.jit(lambda q: signals_from_probs(
+        q, jnp.zeros(lead), 0.4, 1))(p)
+    want = np.asarray(jax.lax.top_k(p, 2)[0])
+    for k, name in enumerate(("top1", "top2")):
+        got = np.asarray(sig[name])
+        assert got.shape == lead
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want[..., k].view(np.uint32))

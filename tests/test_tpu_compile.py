@@ -127,3 +127,7 @@ def test_compiles_for_one_v5e_chip(name, shapes):
     # the weights are bf16 at published widths: the target alone is >6 GB
     if name != "draft_decode":
         assert mem.argument_size_in_bytes > 6e9, mem.argument_size_in_bytes
+    if name == "fused_tick":
+        # the arms' top-1/top-2 are reductions; a sort over the vocabulary
+        # in the draft loop cost about 19 ms of every tick on a v5e
+        assert "sort(" not in compiled.as_text()

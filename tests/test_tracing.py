@@ -124,9 +124,10 @@ def test_host_spans_count_and_nest(served):
 
 
 def test_device_scopes_in_lowered_programs(served):
-    """(b) The fused tick's ops carry the ``draft``, ``verify``, ``accept``
-    (inside ``verify``) and ``rollback`` scopes in their ``op_name``
-    metadata; the prefill chunk program's forward carries ``prefill``."""
+    """(b) The fused tick's ops carry the ``draft``, ``signals`` (inside
+    ``draft``), ``verify``, ``accept`` (inside ``verify``) and ``rollback``
+    scopes in their ``op_name`` metadata; the prefill chunk program's
+    forward carries ``prefill``."""
     eng = served[2].engine
     B, g = eng.batch_size, eng.gamma_max
     keys = jax.random.split(jax.random.PRNGKey(0), B)
@@ -139,10 +140,13 @@ def test_device_scopes_in_lowered_programs(served):
         jnp.zeros((B,), bool), lens, lens, lens, arms=eng.controller.arms,
         gamma_max=g, temperature=0.0, greedy=True, n_prompt_tokens=2,
         paged=True).as_text(dialect="hlo", debug_info=True)
-    for scope in ("/rollback/", "/draft/", "/verify/", "/verify/accept/"):
+    for scope in ("/rollback/", "/draft/", "/verify/", "/verify/accept/",
+                  "/signals/"):
         assert f'op_name="jit(fused_session_tick)' in tick
         assert any(scope in line for line in tick.splitlines()
                    if "op_name=" in line), scope
+    assert all("/draft/" in line for line in tick.splitlines()
+               if "/signals/" in line)
     prefill = chunk_prefill_paged.lower(
         eng.target.params, eng.target.cfg, eng.tspec,
         eng._lane_view(eng.tcache, 0), jnp.zeros((1, CHUNK), jnp.int32),
