@@ -3,8 +3,11 @@ window, check what the window served against the plain reference.
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is found by name from ``BENCHMARK.json``: the configuration's file
-(``configs/<name>.json``), the traffic file (``traffic/<name>.json``, read
-by ``gen/traffic.py``) and one reader per metric (``metrics/<name>.py``).
+(``configs/<name>.json``), the model family module it names under
+``reference`` (``reference/<name>.py``: the program's config, the weights,
+the reference forward and the least-work dims), the traffic file
+(``traffic/<name>.json``, read by ``gen/traffic.py``) and one reader per
+metric (``metrics/<name>.py``).
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ import tempfile
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -34,6 +38,8 @@ SPANS = ("bench.step", "engine.session_step_flush", "server.release_finished",
          "scheduler.schedule", "engine.session_step_launch", "client.poll",
          "client.submit", "client.idle")
 WARMUP_TIMEOUT_S = 900.0
+# what a model family module gives (``reference/__init__.py``)
+FAMILY_API = ("Shape", "make_params", "rows", "program_config", "dims")
 
 
 class BenchError(RuntimeError):
@@ -56,6 +62,7 @@ class Cell:
     per_layer: List[dict]
     root: Path
     bench_dir: Path
+    family: ModuleType
 
     def reader(self, metric: str) -> Callable:
         path = self.bench_dir / "metrics" / f"{metric}.py"
@@ -87,26 +94,26 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     e2e = _for_cell(bench["end_to_end"], workload)
     return Cell(workload, int(w["chips"]), w["config"], config, w["traffic"],
                 traffic, check, e2e, _for_cell(bench["per_layer"], workload),
-                root, bench_dir)
+                root, bench_dir, family_module(config))
 
 
 # ---------------------------------------------------------------- models
 
-def _reference_module(config: dict):
-    return importlib.import_module(
-        f"{__package__}.reference.{config['reference']}")
-
-
-def model_config(s, name: str):
-    """The program's ``ModelConfig`` for the sizes ``s`` (a reference
-    module's ``Shape``) of a dense decoder."""
-    from repro.models import ModelConfig
-    return ModelConfig(
-        name=name, arch_type="dense", num_layers=s.layers, d_model=s.d,
-        num_heads=s.heads, num_kv_heads=s.kv_heads, head_dim=s.head_dim,
-        d_ff=s.d_ff, vocab_size=s.vocab, activation="swiglu",
-        qk_norm=s.qk_norm, qkv_bias=s.qkv_bias, rope_theta=s.rope_theta,
-        rms_eps=s.eps, tie_embeddings=True, block_pattern=("attn",))
+def family_module(config: dict) -> ModuleType:
+    """The model family module the configuration names under
+    ``reference``, with everything ``FAMILY_API`` lists."""
+    name = f"{__package__}.reference.{config['reference']}"
+    try:
+        mod = importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise BenchError(f"no model family module {name}") from e
+    missing = [a for a in FAMILY_API if not hasattr(mod, a)]
+    if missing:
+        raise BenchError(f"model family module {name} lacks "
+                         f"{', '.join(missing)}")
+    return mod
 
 
 def seed_words(seed: int, stream: int) -> Tuple[int, int]:
@@ -130,8 +137,8 @@ class Layout:
     mean_reserve_tokens: float
 
     @classmethod
-    def of(cls, config: dict, traffic: dict, target: counts.Dims,
-           draft: counts.Dims) -> "Layout":
+    def of(cls, config: dict, traffic: dict, target: counts.ModelDims,
+           draft: counts.ModelDims) -> "Layout":
         srv = config["serving"]
         block, gamma = int(srv["block_size"]), int(srv["gamma_max"])
         worst = int(traffic["prompt"]["hi"]) + int(traffic["output"]["hi"]) + gamma + 2
@@ -351,8 +358,8 @@ class RunView:
     logs: Dict[int, ReqLog]
     t0: float
     t1: float
-    target: counts.Dims
-    draft: counts.Dims
+    target: counts.ModelDims
+    draft: counts.ModelDims
     peak: dict
     trace: Optional[object] = None
 
@@ -439,10 +446,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from repro.core import EngineSpec, ModelBundle, make_controller
     from repro.serving.engine import SpecServer
 
-    ref = _reference_module(cell.config)
+    ref = cell.family
     tshape = ref.Shape.from_config(cell.config["model"])
     dshape = ref.Shape.from_config(cell.config["draft"])
-    tdims, ddims = counts.Dims.of(tshape), counts.Dims.of(dshape)
+    tdims, ddims = ref.dims(tshape), ref.dims(dshape)
     srv_cfg = cell.config["serving"]
     lay = Layout.of(cell.config, cell.traffic, tdims, ddims)
     gamma = int(srv_cfg["gamma_max"])
@@ -452,8 +459,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     tparams = ref.make_params(tshape, seed_words(seed, 10), cell.config["weights"])
     dparams = ref.make_params(dshape, seed_words(seed, 11), cell.config["weights"])
     jax.block_until_ready((tparams, dparams))
-    tcfg = model_config(tshape, cell.config_name)
-    dcfg = model_config(dshape, cell.config_name + "-draft")
+    tcfg = ref.program_config(tshape, cell.config_name)
+    dcfg = ref.program_config(dshape, cell.config_name + "-draft")
     ctrl = make_controller(srv_cfg["controller"], gamma_max=gamma,
                            seed=int(seed % (2 ** 31)))
     spec = EngineSpec(backend="paged", batch_size=lay.lanes,

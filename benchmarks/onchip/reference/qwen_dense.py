@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import counts
+
 WEIGHT_STDS = ("embed_std", "norm_std", "bias_std")
 
 
@@ -71,6 +73,23 @@ class Shape(NamedTuple):
                    float(m["rope_theta"]), float(m["rms_norm_eps"]),
                    bool(m.get("attention_bias", arch == "Qwen2ForCausalLM")),
                    arch == "Qwen3ForCausalLM")
+
+
+def program_config(s: Shape, name: str):
+    """The program's ``ModelConfig`` for the sizes ``s``."""
+    from repro.models import ModelConfig
+    return ModelConfig(
+        name=name, arch_type="dense", num_layers=s.layers, d_model=s.d,
+        num_heads=s.heads, num_kv_heads=s.kv_heads, head_dim=s.head_dim,
+        d_ff=s.d_ff, vocab_size=s.vocab, activation="swiglu",
+        qk_norm=s.qk_norm, qkv_bias=s.qkv_bias, rope_theta=s.rope_theta,
+        rms_eps=s.eps, tie_embeddings=True, block_pattern=("attn",))
+
+
+def dims(s: Shape) -> counts.Dims:
+    """What the least-work counts read of the sizes ``s``."""
+    return counts.Dims(s.layers, s.d, s.heads, s.kv_heads, s.head_dim,
+                       s.d_ff, s.vocab, s.qkv_bias, s.qk_norm)
 
 
 # ------------------------------------------------------------- weights

@@ -18,6 +18,7 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import copy  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -49,7 +50,7 @@ def main(argv=None) -> int:
         return 2
     base = load_cell(args.workload, ROOT)
     for rate in (float(r) for r in args.rates.split(",")):
-        cell = copy.deepcopy(base)
+        cell = dataclasses.replace(base, traffic=copy.deepcopy(base.traffic))
         cell.traffic["arrivals"]["rate"] = rate
         out = run_cell(cell, args.seed, args.seconds, False,
                        t_process=time.perf_counter(), chip_kind=dev.device_kind,

@@ -10,7 +10,7 @@ import time
 import numpy as np
 import pytest
 
-from conftest import BENCH, ROOT, TINY_CLOSED, make_root, tiny_config
+from .conftest import BENCH, ROOT, TINY_CLOSED, make_root, tiny_config
 from onchip import harness
 
 RUN = BENCH / "run.py"
@@ -57,8 +57,7 @@ def test_every_cell_resolves_its_files(cell):
     # no per-layer metric in a cell that lacks the end-to-end metric it moves
     assert {m["moves"] for m in c.per_layer} <= names
     lay = harness.Layout.of(c.config, c.traffic,
-                            *(harness.counts.Dims.of(harness._reference_module(
-                                c.config).Shape.from_config(c.config[k]))
+                            *(c.family.dims(c.family.Shape.from_config(c.config[k]))
                               for k in ("model", "draft")))
     assert lay.lanes >= 1 and lay.pool_tokens >= lay.slot_tokens
 

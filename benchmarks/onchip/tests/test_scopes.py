@@ -6,7 +6,7 @@ import json
 
 import pytest
 
-from conftest import BENCH, ROOT, tiny_config
+from .conftest import BENCH, ROOT, tiny_config
 from onchip import counts, harness, scopes, trace
 
 DEV, HOST = "/device:TPU:0", "/host:CPU"
@@ -237,10 +237,10 @@ def test_accepted_readers_unchanged_on_the_recorded_trace():
     """The four accepted per-layer metrics read what they read before the
     program wrote spans of its own: the recorded chip trace (harness spans
     only) and a fixed record give the values pinned here."""
-    from onchip.reference.qwen_dense import Shape
     cfg = tiny_config()
-    tdims = counts.Dims.of(Shape.from_config(cfg["model"]))
-    ddims = counts.Dims.of(Shape.from_config(cfg["draft"]))
+    fam = harness.family_module(cfg)
+    tdims = fam.dims(fam.Shape.from_config(cfg["model"]))
+    ddims = fam.dims(fam.Shape.from_config(cfg["draft"]))
     rec = harness.Record()
     rec.admissions += [(0.01, 40), (0.05, 24)]
     for i in range(1, 9):

@@ -5,10 +5,9 @@ import json
 import numpy as np
 import pytest
 
-from conftest import BENCH, ROOT, TINY_CLOSED
+from .conftest import BENCH, ROOT, TINY_CLOSED
 from onchip import counts, harness, trace
 from onchip.gen.traffic import make_traffic, pool_lengths
-from onchip.reference.qwen_dense import Shape
 
 
 def _log(rid, due, first):
@@ -98,7 +97,8 @@ def test_ttft_from_due_time():
 
 def _dims(name, which="model"):
     c = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    return counts.Dims.of(Shape.from_config(c[which]))
+    fam = harness.family_module(c)
+    return fam.dims(fam.Shape.from_config(c[which]))
 
 
 def test_counts_qwen25_3b_by_hand():
